@@ -29,6 +29,10 @@ from dataclasses import dataclass
 
 from repro.configs.base import get_config
 from repro.data.synthetic import mixed_cifar, mixed_noniid
+from repro.utils.compile_cache import enable_compile_cache
+
+# every benchmark imports this module before it compiles anything
+enable_compile_cache()
 
 
 @dataclass(frozen=True)
@@ -73,13 +77,20 @@ def ensure_host_devices(n: int) -> int:
     XLA reads ``--xla_force_host_platform_device_count`` ONCE, when the
     backend initializes — so this only works if no jax computation ran
     yet in this process (benchmark ``__main__``s call it first thing).
-    Returns the device count actually available; callers emit their
-    multi-device rows only when it matches."""
+    On an accelerator backend it refuses: emulated CPU devices would
+    stand in for the real chips.  Returns the device count actually
+    available; callers emit their multi-device rows only when it
+    matches."""
     import jax
     flags = os.environ.get("XLA_FLAGS", "")
     if n > 1 and "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + f" --xla_force_host_platform_device_count={n}").strip()
+    if n > 1 and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"--devices={n} emulates CPU devices, but the backend is "
+            f"{jax.default_backend()} with {jax.device_count()} "
+            "device(s); run without --devices to use them")
     got = jax.device_count()
     if n > 1 and got != n:
         print(f"[devices: wanted {n}, backend has {got} — "

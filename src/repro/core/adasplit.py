@@ -40,8 +40,8 @@ cohort; see rung 5).  The ladder rungs:
    with NO host round-trip in between.  Selection is the pure
    functional orchestrator (``core.orchestrator.ucb_*``): the
    (N,)-state UCB pytree rides in the scan carry next to the stacked
-   client/server/mask/opt pytrees (all donated off-CPU, so XLA updates
-   them in place), and ``top_k`` with keyed jitter picks the eta*N
+   client/server/mask/opt pytrees (all donated, so XLA updates them in
+   place), and ``top_k`` with keyed jitter picks the eta*N
    clients in-graph.  The round's data is staged once as a
    (T, C, B, ...) device array; per-iteration CE losses, payload nnz
    fractions and selection indices come back as stacked (T, k)
@@ -144,13 +144,12 @@ step (reproduces the seed's per-client loop bit-for-bit);
 implementations for the differential tests and benchmarks
 (``benchmarks/round_scan.py``, ``benchmarks/global_phase.py``).
 ``fused_mask_adam`` routes the per-client mask updates through the
-fused Pallas masked-Adam kernel on TPU (``kernels/masked_adam``),
-falling back to ``adam_update`` elsewhere; ``fused_server_adam`` does
-the same for the server optimizer step under the same
-TPU-native/fallback gating.  Both default to ``None`` = backend-aware:
-auto-ON when ``jax.default_backend() == "tpu"`` (where the kernels are
-native), auto-OFF elsewhere; an explicit True/False always wins
-(``_fused_default``).
+fused Pallas masked-Adam kernel (``kernels/masked_adam``);
+``fused_server_adam`` does the same for the server optimizer step.
+Both default to ``None`` = backend-aware: ON when
+``jax.default_backend() == "tpu"`` (where the kernel lowers natively),
+OFF elsewhere; an explicit False always wins, and an explicit True on
+a backend that cannot lower the kernel raises (``_fused_default``).
 
 ``batched_conv=True`` (default) lowers every per-client conv in the hot
 path — the vmapped client step, the joint step's client part, the
@@ -179,7 +178,6 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -248,8 +246,17 @@ class AdaSplitHParams:
 def _fused_default(flag: Optional[bool], on_tpu: bool) -> bool:
     """Backend-aware default for the fused Pallas Adam kernels: ``None``
     resolves to on iff running on TPU (where the kernels lower
-    natively); an explicit True/False always wins."""
-    return on_tpu if flag is None else bool(flag)
+    natively); an explicit False always wins, and an explicit True on
+    a backend that cannot lower the kernel is an error — never a
+    silent plain-Adam run."""
+    if flag is None:
+        return on_tpu
+    if flag and not on_tpu:
+        raise ValueError(
+            "fused Pallas Adam requested on the "
+            f"{jax.default_backend()!r} backend, which cannot lower it "
+            "natively; pass None (backend default) or False")
+    return bool(flag)
 
 
 def _proj_init(key, in_dim, proj_dim):
@@ -538,11 +545,10 @@ class AdaSplitTrainer:
         on_tpu = jax.default_backend() == "tpu"
 
         def gated_adam(fused: bool):
-            """Adam step behind the shared TPU-native/fallback gate:
-            the fused Pallas kernel (one HBM pass per leaf) when the
-            flag is on AND we're on TPU, plain ``adam_update``
-            elsewhere (bit-identical fallback)."""
-            if fused and on_tpu:
+            """Adam step: the fused Pallas kernel (one HBM pass per
+            leaf, native lowering) when the resolved flag is on, plain
+            ``adam_update`` otherwise."""
+            if fused:
                 from repro.kernels.masked_adam import fused_adam_update
 
                 def step(p, g, o):
@@ -787,9 +793,17 @@ class AdaSplitTrainer:
             return accuracy(logits, y)
 
         self._eval_client = jax.jit(eval_client)
-        # all clients at once (single device round-trip per evaluate())
-        self._eval_all = jax.jit(jax.vmap(eval_client,
-                                          in_axes=(0, None, 0, 0, 0)))
+        # all clients at once (single device round-trip per evaluate());
+        # sharded, each shard evaluates its own clients — Pallas kernels
+        # are not partitioned automatically
+        eval_all = jax.vmap(eval_client, in_axes=(0, None, 0, 0, 0))
+        if self._shard and not self._streamed:
+            coh = P(self._ax.data_spec)
+            eval_all = jax.shard_map(
+                eval_all, mesh=self._mesh,
+                in_specs=(coh, P(), coh, coh, coh), out_specs=coh,
+                check_vma=False)
+        self._eval_all = jax.jit(eval_all)
 
     # ------------------------------------------------------------------
     # device-resident round: fused iteration + lax.scan over T
@@ -882,8 +896,10 @@ class AdaSplitTrainer:
     def _round_fn(self, T: int, global_phase: bool):
         """One jitted fn running a whole round: scan of the fused
         client-step -> select -> global-step -> UCB-update iteration.
-        Cached per (T, global_phase); carries are donated off-CPU so
-        XLA updates the stacked param/opt/mask pytrees in place."""
+        Cached per (T, global_phase); carries are donated on every
+        backend so XLA updates the stacked param/opt/mask pytrees in
+        place (and a stale read of a donated buffer fails in CPU tests
+        as it would on the chip)."""
         cache_key = (T, global_phase)
         if cache_key in self._round_fns:
             return self._round_fns[cache_key]
@@ -902,8 +918,7 @@ class AdaSplitTrainer:
                                 unroll=unroll)
 
         round_fn = self._wrap_shard_map(round_fn, staged_cohort_dim=1)
-        donate = () if on_cpu else (0,)
-        fn = jax.jit(round_fn, donate_argnums=donate)
+        fn = jax.jit(round_fn, donate_argnums=(0,))
         self._round_fns[cache_key] = fn
         return fn
 
@@ -913,25 +928,25 @@ class AdaSplitTrainer:
         (dim ``staged_cohort_dim``) on ``data``, iteration counters and
         the stacked (idx, ces, fracs) outputs replicated (every shard
         computes the identical selection / CE / nnz values, so P() out
-        specs just take the one copy).  ``check_rep=False``: the body
+        specs just take the one copy).  ``check_vma=False``: the body
         mixes manual collectives with replicated compute, which the
-        static replication checker can't see through."""
+        static varying-axes checker can't see through."""
         if not self._shard:
             return fn
         data_spec = staged_cohort_spec(self._ax, staged_cohort_dim + 1,
                                        cohort_dim=staged_cohort_dim)
-        return shard_map(
+        return jax.shard_map(
             fn, mesh=self._mesh,
             in_specs=(self._carry_specs, data_spec, data_spec, P()),
             out_specs=(self._carry_specs, P()),
-            check_rep=False)
+            check_vma=False)
 
     def _epoch_fn(self, R: int, T: int, global_phase: bool):
         """One jitted fn running R whole rounds: an outer scan whose
         body applies ``ucb_new_round`` IN-GRAPH at the round boundary
         and then runs the round's inner iteration scan — R x T fused
         iterations per dispatch, zero host round-trips.  Cached per
-        (R, T, global_phase); carries donated off-CPU."""
+        (R, T, global_phase); carries donated."""
         cache_key = ("epoch", R, T, global_phase)
         if cache_key in self._round_fns:
             return self._round_fns[cache_key]
@@ -966,12 +981,9 @@ class AdaSplitTrainer:
             return jax.lax.scan(round_body, carry, (xs_ep, ys_ep, t_ep))
 
         epoch_fn = self._wrap_shard_map(epoch_fn, staged_cohort_dim=2)
-        # Donate the carry on EVERY backend (unlike the per-round fn,
-        # which keeps the PR-2 CPU behavior as the baseline): the epoch
-        # carry only ever flows forward — into the next chunk's
-        # dispatch or back into the trainer state — and donation lets
-        # XLA alias the big stacked param/opt pytrees in place
-        # (measured ~8% per-iteration on the 2-core CPU box alone).
+        # The epoch carry only ever flows forward — into the next
+        # chunk's dispatch or back into the trainer state — so it is
+        # donated and XLA aliases the stacked param/opt pytrees in place.
         fn = jax.jit(epoch_fn, donate_argnums=(0,))
         self._round_fns[cache_key] = fn
         return fn
@@ -1130,7 +1142,9 @@ class AdaSplitTrainer:
         """One jitted fn scanning the vmapped client step over a round's
         T iterations for an (m, ...)-row streamed chunk, returning the
         updated rows + the stacked (T, m, B, ...) split activations.
-        Cached per (T, m); chunk rows are donated off-CPU."""
+        A chunk placed with its cohort axis on ``data`` runs under
+        ``shard_map`` (Pallas kernels are not partitioned
+        automatically).  Cached per (T, m); chunk rows are donated."""
         cache_key = ("stream", T, m)
         if cache_key in self._round_fns:
             return self._round_fns[cache_key]
@@ -1149,8 +1163,14 @@ class AdaSplitTrainer:
                                           unroll=unroll)
             return cp, co, acts
 
-        donate = () if on_cpu else (0, 1)
-        fn = jax.jit(chunk_fn, donate_argnums=donate)
+        if self._shard and m % self._ax.data_size == 0:
+            rows = P(self._ax.data_spec)                 # (m, ...) leaves
+            staged = P(None, self._ax.data_spec)         # (T, m, ...)
+            chunk_fn = jax.shard_map(
+                chunk_fn, mesh=self._mesh,
+                in_specs=(rows, rows, staged, staged),
+                out_specs=(rows, rows, staged), check_vma=False)
+        fn = jax.jit(chunk_fn, donate_argnums=(0, 1))
         self._round_fns[cache_key] = fn
         return fn
 

@@ -133,7 +133,9 @@ def ucb_new_round(state: dict, *, gamma: float) -> dict:
         "l_disc": last * (1.0 + gamma),
         "s_disc": ones * (1.0 + gamma),
         "last": last,
-        "prev": last,
+        # its own buffer: the scan drivers donate this state, and one
+        # buffer donated twice is an error
+        "prev": jnp.copy(last),
         "t": jnp.asarray(2, jnp.int32),
     }
 

@@ -24,9 +24,10 @@ with two lowerings:
   GEMMs.  This is the autodiff primal used by training.
 * ``method="pallas"`` — the same contraction as a TPU-native
   ``pallas_call`` (one (bm, K*K*Cin) patch panel x (K*K*Cin, Cout)
-  filter panel per grid step, f32 MXU accumulation), following the
-  ``masked_adam.py`` pattern: native lowering on TPU, interpret mode on
-  CPU for parity tests.  A custom VJP routes its backward through the
+  filter panel per grid step, f32 MXU accumulation).  It lowers
+  natively (``interpret=False``, the default, which only a TPU
+  backend can compile); CPU parity tests pass ``interpret=True``
+  explicitly.  A custom VJP routes its backward through the
   einsum-form batched GEMMs.
 
 ``method="conv"`` keeps the vmapped ``lax.conv_general_dilated``
@@ -55,6 +56,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def default_method() -> str:
+    """``method=None``'s resolution: the Pallas kernel where it lowers
+    natively (TPU), the einsum GEMM elsewhere."""
     return "pallas" if jax.default_backend() == "tpu" else "einsum"
 
 
@@ -109,7 +112,7 @@ def _gemm_kernel(a_ref, b_ref, o_ref):
                          ).astype(o_ref.dtype)[None]
 
 
-def panel_gemm_2d(a, b, *, block_m: int = 128, interpret: bool = True):
+def panel_gemm_2d(a, b, *, block_m: int = 128, interpret: bool = False):
     """(C, M, K) @ (C, K, N) -> (C, M, N), one (1, bm, K) x (1, K, N)
     MXU tile per grid step.  M/K/N must already be padded to tile
     multiples (M % bm == 0; K, N % 128 == 0 for the native lowering)."""
@@ -126,6 +129,7 @@ def panel_gemm_2d(a, b, *, block_m: int = 128, interpret: bool = True):
         out_specs=pl.BlockSpec((1, bm, N), lambda c, m: (c, m, 0)),
         out_shape=jax.ShapeDtypeStruct((C, M, N), a.dtype),
         interpret=interpret,
+        name="panel_gemm",
     )(a, b)
 
 
@@ -138,10 +142,8 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, cfg)
 
 
-def _panel_gemm_fwd(a, b, interpret=None):
+def _panel_gemm_fwd(a, b, interpret):
     """Tile-padded pallas dispatch: a (C, M, K) @ b (C, K, N)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     M, N = a.shape[1], b.shape[2]
     ap = _pad_to(_pad_to(a, 2, 128), 1, 128)
     bp = _pad_to(_pad_to(b, 1, 128), 2, 128)
@@ -149,19 +151,19 @@ def _panel_gemm_fwd(a, b, interpret=None):
     return out[:, :M, :N]
 
 
-@jax.custom_vjp
-def panel_gemm(a, b):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def panel_gemm(a, b, interpret=False):
     """Batched GEMM through the Pallas kernel; backward through the
     einsum-form batched GEMMs (a dot_general's transpose is another
     dot_general — no grouped lowering anywhere)."""
-    return _panel_gemm_fwd(a, b)
+    return _panel_gemm_fwd(a, b, interpret)
 
 
-def _panel_gemm_vjp_fwd(a, b):
-    return _panel_gemm_fwd(a, b), (a, b)
+def _panel_gemm_vjp_fwd(a, b, interpret):
+    return _panel_gemm_fwd(a, b, interpret), (a, b)
 
 
-def _panel_gemm_vjp_bwd(res, g):
+def _panel_gemm_vjp_bwd(interpret, res, g):
     a, b = res
     da = jnp.einsum("cmn,ckn->cmk", g, b).astype(a.dtype)
     db = jnp.einsum("cmk,cmn->ckn", a, g).astype(b.dtype)
@@ -178,16 +180,20 @@ panel_gemm.defvjp(_panel_gemm_vjp_fwd, _panel_gemm_vjp_bwd)
 
 def _gemm_bias_relu_kernel(a_ref, b_ref, bias_ref, o_ref):
     z = jnp.dot(a_ref[0], b_ref[0], preferred_element_type=jnp.float32)
-    z = z + bias_ref[0].astype(jnp.float32)
+    z = z + bias_ref[0].astype(jnp.float32)          # (1, N) row
     o_ref[...] = jnp.maximum(z, 0).astype(o_ref.dtype)[None]
 
 
 def panel_gemm_bias_relu_2d(a, b, bias, *, block_m: int = 128,
-                            interpret: bool = True):
+                            interpret: bool = False):
     """relu((C, M, K) @ (C, K, N) + bias (C, N)) with the bias add and
     ReLU fused into the GEMM epilogue — the accumulator tile is
     rectified in registers before the HBM writeback, instead of a
-    separate elementwise pass re-reading the (C, M, N) output."""
+    separate elementwise pass re-reading the (C, M, N) output.
+
+    The bias travels as (C, 1, N) so its block's last two dims, (1, N),
+    equal the array's: Mosaic refuses a (1, N) block over (C, N)
+    unless C is a multiple of 8."""
     C, M, K = a.shape
     N = b.shape[-1]
     bm = min(block_m, M)
@@ -198,16 +204,15 @@ def panel_gemm_bias_relu_2d(a, b, bias, *, block_m: int = 128,
         grid=grid,
         in_specs=[pl.BlockSpec((1, bm, K), lambda c, m: (c, m, 0)),
                   pl.BlockSpec((1, K, N), lambda c, m: (c, 0, 0)),
-                  pl.BlockSpec((1, N), lambda c, m: (c, 0))],
+                  pl.BlockSpec((1, 1, N), lambda c, m: (c, 0, 0))],
         out_specs=pl.BlockSpec((1, bm, N), lambda c, m: (c, m, 0)),
         out_shape=jax.ShapeDtypeStruct((C, M, N), a.dtype),
         interpret=interpret,
-    )(a, b, bias)
+        name="panel_gemm_bias_relu",
+    )(a, b, bias.reshape(C, 1, N))
 
 
-def _panel_gemm_fused_fwd(a, b, bias, interpret=None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def _panel_gemm_fused_fwd(a, b, bias, interpret):
     M, N = a.shape[1], b.shape[2]
     ap = _pad_to(_pad_to(a, 2, 128), 1, 128)
     bp = _pad_to(_pad_to(b, 1, 128), 2, 128)
@@ -216,23 +221,23 @@ def _panel_gemm_fused_fwd(a, b, bias, interpret=None):
     return out[:, :M, :N]
 
 
-@jax.custom_vjp
-def panel_gemm_fused(a, b, bias):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def panel_gemm_fused(a, b, bias, interpret=False):
     """``relu(panel_gemm(a, b) + bias[:, None, :])`` through the fused
     Pallas epilogue kernel; backward unchanged — routed through the
     same einsum-form batched GEMMs as :func:`panel_gemm`, with the ReLU
     mask recovered from the saved output (``out > 0`` ⟺ pre-activation
     > 0, the identical subgradient-at-0 convention as ``jax.nn.relu``).
     """
-    return _panel_gemm_fused_fwd(a, b, bias)
+    return _panel_gemm_fused_fwd(a, b, bias, interpret)
 
 
-def _panel_gemm_fused_vjp_fwd(a, b, bias):
-    out = _panel_gemm_fused_fwd(a, b, bias)
+def _panel_gemm_fused_vjp_fwd(a, b, bias, interpret):
+    out = _panel_gemm_fused_fwd(a, b, bias, interpret)
     return out, (a, b, bias, out)
 
 
-def _panel_gemm_fused_vjp_bwd(res, g):
+def _panel_gemm_fused_vjp_bwd(interpret, res, g):
     a, b, bias, out = res
     dz = jnp.where(out > 0, g, 0)
     da = jnp.einsum("cmn,ckn->cmk", dz, b).astype(a.dtype)
@@ -262,7 +267,7 @@ def broadcast_bias(bias):
 
 
 def client_conv(x, w, *, method: str | None = None, bias=None,
-                fused_epilogue: bool = False):
+                fused_epilogue: bool = False, interpret: bool = False):
     """Stacked-client KxK "same" conv, client axis optional.
 
     x: (C, B, H, W, Cin) with w (C, K, K, Cin, Cout), or unstacked
@@ -277,6 +282,9 @@ def client_conv(x, w, *, method: str | None = None, bias=None,
     "conv" paths apply the identical ``relu(. + bias)`` epilogue as
     plain XLA ops (same float ops in the same order as the unfused
     caller-side bias+ReLU, so CPU training paths are bit-unchanged).
+
+    ``interpret=True`` runs the "pallas" kernels through the Pallas
+    interpreter (CPU parity tests); the XLA methods ignore it.
     """
     if method is None:
         method = default_method()
@@ -301,13 +309,13 @@ def client_conv(x, w, *, method: str | None = None, bias=None,
         bias = bias.astype(x.dtype)
         if w.ndim == 4:                  # unstacked: batch of one panel
             out = panel_gemm_fused(patches[None], panels[None],
-                                   bias[None])[0]
+                                   bias[None], interpret)[0]
         else:
-            out = panel_gemm_fused(patches, panels, bias)
+            out = panel_gemm_fused(patches, panels, bias, interpret)
     elif w.ndim == 4:
-        out = panel_gemm(patches[None], panels[None])[0]
+        out = panel_gemm(patches[None], panels[None], interpret)[0]
     else:
-        out = panel_gemm(patches, panels)
+        out = panel_gemm(patches, panels, interpret)
     return out.reshape(out_shape)
 
 

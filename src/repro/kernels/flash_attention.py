@@ -73,7 +73,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q: (B, Hq, S, hd); k, v: (B, Hkv, S, hd).  Returns (B, Hq, S, hd).
 
     S must divide by the block sizes (the launcher pads); GQA via

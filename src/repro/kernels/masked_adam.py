@@ -34,7 +34,7 @@ def _kernel(sc_ref, p_ref, g_ref, mu_ref, nu_ref, mask_ref,
 
 
 def masked_adam_2d(p, g, mu, nu, mask, *, lr, b1, b2, eps, b1t, b2t,
-                   block=(256, 256), interpret: bool = True):
+                   block=(256, 256), interpret: bool = False):
     """All operands (M, N); b1t/b2t are traced scalars (1 - beta^t).
 
     mask=None lowers the no-mask variant (plain fused Adam): the fifth
@@ -65,6 +65,7 @@ def masked_adam_2d(p, g, mu, nu, mask, *, lr, b1, b2, eps, b1t, b2t,
                    jax.ShapeDtypeStruct((M, N), jnp.float32),
                    jax.ShapeDtypeStruct((M, N), jnp.float32)],
         interpret=interpret,
+        name="masked_adam",
     )(scalars, *operands)
     return new_p, new_mu, new_nu
 
@@ -76,7 +77,7 @@ def _nomask_kernel(kernel, sc_ref, p_ref, g_ref, mu_ref, nu_ref,
 
 
 def masked_adam(p, g, mu, nu, mask, *, lr, b1=0.9, b2=0.999, eps=1e-8,
-                step=1, interpret: bool = True):
+                step=1, interpret: bool = False):
     """Any-rank wrapper (reshapes to 2D panels; pads to tile multiples).
 
     mask may be None (plain fused Adam, 4 streamed inputs)."""
@@ -109,19 +110,17 @@ def masked_adam(p, g, mu, nu, mask, *, lr, b1=0.9, b2=0.999, eps=1e-8,
 
 
 def fused_adam_update(params, grads, state, *, lr, b1=0.9, b2=0.999,
-                      eps=1e-8, mask=None, interpret: bool = None):
+                      eps=1e-8, mask=None, interpret: bool = False):
     """Drop-in ``optim.adam.adam_update`` twin running every leaf
     through the fused kernel (one HBM pass per leaf instead of ~3).
 
     ``state`` is an ``adam_init`` dict; ``mask`` an optional pytree of
     multiplicative gradient masks (defaults to all-ones — plain Adam).
-    Trainers gate the call site on the backend (``fused_mask_adam``
-    hparam in core/adasplit.py): native lowering on TPU, and the caller
-    falls back to ``adam_update`` elsewhere.  ``interpret=True`` runs
-    the same kernel through the Pallas interpreter for CPU validation.
+    The kernel lowers natively, which only a TPU backend compiles
+    (``fused_mask_adam`` / ``fused_server_adam`` in core/adasplit.py
+    refuse it elsewhere); ``interpret=True`` runs the same kernel
+    through the Pallas interpreter for CPU validation.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     step = state["step"] + 1
     flat_p, treedef = jax.tree.flatten(params)
     flat_g = treedef.flatten_up_to(grads)

@@ -58,7 +58,7 @@ def _kernel(q_blk_ref, rows_ref, q_all_ref, labels_ref, lse_ref,
 
 
 def ntxent_stats(q, labels, tau: float = 0.07, *, block_rows: int = 128,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """Returns (lse, pos_sum, pos_cnt), each (B,) float32.
 
     q: (B, D); labels: (B,) int32.  B is padded up to a multiple of
@@ -91,7 +91,7 @@ def ntxent_stats(q, labels, tau: float = 0.07, *, block_rows: int = 128,
 
 
 def ntxent_loss(q, labels, tau: float = 0.07, *, normalize: bool = True,
-                interpret: bool = True):
+                interpret: bool = False):
     """Kernel-backed supervised NT-Xent loss (mean over positive pairs)."""
     qf = q.astype(jnp.float32)
     if normalize:

@@ -21,7 +21,7 @@ def _kernel(x_ref, o_ref, *, threshold: float):
 
 
 def soft_threshold_2d(x, threshold: float, *, block: tuple = (256, 256),
-                      interpret: bool = True):
+                      interpret: bool = False):
     """x: (M, N) -> soft-thresholded, tiled (bm, bn) blocks in VMEM."""
     M, N = x.shape
     bm, bn = min(block[0], M), min(block[1], N)
@@ -39,7 +39,7 @@ def soft_threshold_2d(x, threshold: float, *, block: tuple = (256, 256),
     return out[:M, :N]
 
 
-def soft_threshold(x, threshold: float, *, interpret: bool = True):
+def soft_threshold(x, threshold: float, *, interpret: bool = False):
     """Any-rank wrapper: flattens to 2D tiles."""
     shape = x.shape
     n = x.size

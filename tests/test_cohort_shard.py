@@ -155,7 +155,6 @@ def test_sharded_ucb_select_property():
     replicated top-k) is BITWISE the host ``ucb_select`` for random
     advantage states, including near-tie blocks the keyed jitter has
     to break."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.orchestrator import (ucb_advantage, ucb_select,
                                          ucb_select_from_advantage)
@@ -170,9 +169,9 @@ def test_sharded_ucb_select_property():
         adv = jax.lax.all_gather(ucb_advantage(state), "data", tiled=True)
         return ucb_select_from_advantage(adv, k, key)
 
-    fn = jax.jit(shard_map(sharded_select, mesh=mesh,
-                           in_specs=(state_specs, P()), out_specs=P(),
-                           check_rep=False))
+    fn = jax.jit(jax.shard_map(sharded_select, mesh=mesh,
+                               in_specs=(state_specs, P()), out_specs=P(),
+                               check_vma=False))
     rng = np.random.default_rng(0)
     for case in range(8):
         l = rng.normal(50, 40, n).astype(np.float32)

@@ -3,9 +3,9 @@ training (hypothesis).
 
 Satellites of the epoch-scan PR: the in-graph ``ucb_new_round`` at the
 scan's round boundary must match R host-driven ``new_round()`` calls
-bitwise (discounted sums, jitter keys, selections), and the
-numpy-vectorized batch billing must reproduce the per-event Python
-loop's integer byte totals exactly.
+(selections and jitter keys bitwise, discounted sums to f32 rounding),
+and the numpy-vectorized batch billing must reproduce the per-event
+Python loop's integer byte totals exactly.
 """
 import jax
 import jax.numpy as jnp
@@ -34,8 +34,9 @@ def test_epoch_ucb_round_boundaries_bitwise(data):
     """A jitted scan over R rounds — ``ucb_new_round`` at each boundary,
     ``ucb_select``/``ucb_update`` per iteration, the SAME fold-in key
     schedule — matches R host-driven ``new_round()`` + per-iteration
-    ``select()``/``update()`` calls bitwise: discounted sums, last/prev
-    losses, jittered selections, and the replayed L/S histories."""
+    ``select()``/``update()`` calls: jittered selections and the
+    replayed L/S histories bitwise, discounted sums and last/prev losses
+    to f32 rounding."""
     n = data.draw(st.integers(3, 8), label="n")
     k = data.draw(st.integers(1, n), label="k")
     R = data.draw(st.integers(1, 3), label="R")
@@ -92,10 +93,13 @@ def test_epoch_ucb_round_boundaries_bitwise(data):
     # selections bitwise
     np.testing.assert_array_equal(
         np.asarray(idx_all).reshape(R * T, k), np.stack(sel_host))
-    # functional state bitwise
+    # functional state to a few f32 ULPs: inside the scan XLA may
+    # contract ``gamma * l_disc + new_l`` into one FMA where the host's
+    # eager ops round twice (1 ULP = 6e-8 relative)
     for a, b in zip(jax.tree.leaves(dev.state),
                     jax.tree.leaves(host.state)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6)
     # replayed host histories bitwise
     np.testing.assert_array_equal(dev.L, host.L)
     np.testing.assert_array_equal(dev.S, host.S)

@@ -22,7 +22,7 @@ RNG = np.random.default_rng(42)
 def test_ntxent_matches_oracle(B, D, n_classes):
     q = jnp.asarray(RNG.normal(size=(B, D)), jnp.float32)
     y = jnp.asarray(RNG.integers(0, n_classes, B), jnp.int32)
-    got = float(ops.ntxent_loss(q, y))
+    got = float(ops.ntxent_loss(q, y, interpret=True))
     want = float(ntxent_supervised(q, y))
     assert abs(got - want) < 1e-3 * max(1.0, abs(want))
 
@@ -32,7 +32,7 @@ def test_ntxent_stats_match_ref():
     B, D = 48, 24
     q = jnp.asarray(RNG.normal(size=(B, D)), jnp.float32)
     y = jnp.asarray(RNG.integers(0, 3, B), jnp.int32)
-    lse, ps, pc = ntxent_stats(q, y, 0.07)
+    lse, ps, pc = ntxent_stats(q, y, 0.07, interpret=True)
     rl, rp, rc = ref.ntxent_stats_ref(q, y, 0.07)
     np.testing.assert_allclose(lse, rl, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(ps, rp, rtol=1e-5, atol=1e-5)
@@ -42,7 +42,7 @@ def test_ntxent_stats_match_ref():
 def test_ntxent_no_positives_is_zero():
     q = jnp.asarray(RNG.normal(size=(4, 8)), jnp.float32)
     y = jnp.arange(4, dtype=jnp.int32)  # all distinct labels
-    assert float(ops.ntxent_loss(q, y)) == 0.0
+    assert float(ops.ntxent_loss(q, y, interpret=True)) == 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -60,7 +60,8 @@ def test_flash_attention_matches_oracle(B, Hq, Hkv, S, hd, causal, window):
     q = jnp.asarray(RNG.normal(size=(B, Hq, S, hd)), jnp.float32)
     k = jnp.asarray(RNG.normal(size=(B, Hkv, S, hd)), jnp.float32)
     v = jnp.asarray(RNG.normal(size=(B, Hkv, S, hd)), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=causal, window=window)
+    got = ops.flash_attention(q, k, v, causal=causal, window=window,
+                              interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
 
@@ -71,7 +72,7 @@ def test_flash_attention_dtypes(dtype):
     q = jnp.asarray(RNG.normal(size=(B, Hq, S, hd))).astype(dtype)
     k = jnp.asarray(RNG.normal(size=(B, Hkv, S, hd))).astype(dtype)
     v = jnp.asarray(RNG.normal(size=(B, Hkv, S, hd))).astype(dtype)
-    got = ops.flash_attention(q, k, v)
+    got = ops.flash_attention(q, k, v, interpret=True)
     assert got.dtype == dtype
     want = ref.flash_attention_ref(q, k, v)
     np.testing.assert_allclose(got.astype(jnp.float32),
@@ -89,7 +90,8 @@ def test_flash_attention_vs_model_chunked():
     xla = mha_chunked(q, k, v, causal=True, q_chunk=128, kv_chunk=128)
     krn = ops.flash_attention(q.transpose(0, 2, 1, 3),
                               k.transpose(0, 2, 1, 3),
-                              v.transpose(0, 2, 1, 3), causal=True)
+                              v.transpose(0, 2, 1, 3), causal=True,
+                              interpret=True)
     np.testing.assert_allclose(krn.transpose(0, 2, 1, 3), xla,
                                rtol=2e-2, atol=2e-2)
 
@@ -104,7 +106,7 @@ def test_flash_attention_vs_model_chunked():
 @pytest.mark.parametrize("t", [0.0, 0.1, 1.5])
 def test_soft_threshold(shape, t):
     x = jnp.asarray(RNG.normal(size=shape), jnp.float32)
-    got = ops.soft_threshold(x, t)
+    got = ops.soft_threshold(x, t, interpret=True)
     want = ref.soft_threshold_ref(x, t)
     np.testing.assert_allclose(got, want, atol=1e-6)
 
@@ -117,7 +119,8 @@ def test_masked_adam(shape, step):
     mu = jnp.asarray(RNG.normal(size=shape), jnp.float32) * 0.1
     nu = jnp.abs(jnp.asarray(RNG.normal(size=shape), jnp.float32)) * 0.1
     mask = jnp.asarray(RNG.integers(0, 2, shape), jnp.float32)
-    got = ops.masked_adam(p, g, mu, nu, mask, step=step, lr=1e-3)
+    got = ops.masked_adam(p, g, mu, nu, mask, step=step, lr=1e-3,
+                          interpret=True)
     want = ref.masked_adam_ref(p, g, mu, nu, mask, lr=1e-3, b1=0.9,
                                b2=0.999, eps=1e-8,
                                b1t=1 - 0.9 ** step, b2t=1 - 0.999 ** step)
@@ -130,6 +133,7 @@ def test_masked_adam_zero_mask_freezes_params():
     p = jnp.asarray(RNG.normal(size=shape), jnp.float32)
     g = jnp.asarray(RNG.normal(size=shape), jnp.float32)
     zero = jnp.zeros(shape)
-    new_p, mu, nu = ops.masked_adam(p, g, zero, zero, zero, step=1)
+    new_p, mu, nu = ops.masked_adam(p, g, zero, zero, zero, step=1,
+                                    interpret=True)
     np.testing.assert_allclose(new_p, p)
     np.testing.assert_allclose(mu, 0.0)

@@ -107,3 +107,29 @@ def test_roofline_loader_and_notes(tmp_path):
         assert "| x | train_4k |" in roofline.md_table(recs)
     finally:
         roofline.ARTIFACTS = old
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_directory(monkeypatch, tmp_path, env_set):
+    """``JAX_COMPILATION_CACHE_DIR``, when set, is the only cache
+    directory (nothing else is configured); unset, the cache goes to the
+    fixed, git-ignored ``.jax_cache/`` at the checkout root."""
+    from pathlib import Path
+    from repro.utils import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_set:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            root = Path(compile_cache.__file__).resolve().parents[3]
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert ".jax_cache/" in (root / ".gitignore").read_text()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

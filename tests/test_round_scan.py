@@ -234,10 +234,16 @@ def test_fused_adam_update_matches_adam_update():
 
 
 def test_fused_mask_adam_flag_is_noop_off_tpu(clients6):
-    """On CPU the flag must fall back to adam_update: identical runs."""
+    """Off TPU the backend default (None) resolves to plain adam_update
+    — the same run as an explicit False — and an explicit True is
+    refused: the kernel cannot lower natively here, and nothing falls
+    back to plain Adam behind the caller's back."""
     assert jax.default_backend() != "tpu"
-    on = _train(clients6, rounds=1, kappa=0.0, fused_mask_adam=True)
-    off = _train(clients6, rounds=1, kappa=0.0)
+    with pytest.raises(ValueError, match="fused Pallas Adam"):
+        AdaSplitTrainer(CFG, AdaSplitHParams(fused_mask_adam=True),
+                        clients6)
+    on = _train(clients6, rounds=1, kappa=0.0, fused_mask_adam=None)
+    off = _train(clients6, rounds=1, kappa=0.0, fused_mask_adam=False)
     assert _max_leaf_diff(on.masks, off.masks) == 0.0
     assert _max_leaf_diff(on.server_params, off.server_params) == 0.0
 
@@ -267,11 +273,14 @@ def test_fused_server_adam_interpret_parity():
 
 
 def test_fused_server_adam_flag_is_noop_off_tpu(clients6):
-    """``fused_server_adam`` gates on the backend exactly like the mask
-    flag: off-TPU both settings take the adam_update fallback."""
+    """``fused_server_adam`` resolves exactly like the mask flag: off
+    TPU the default is plain adam_update and an explicit True raises."""
     assert jax.default_backend() != "tpu"
-    on = _train(clients6, rounds=1, kappa=0.0, fused_server_adam=True)
-    off = _train(clients6, rounds=1, kappa=0.0)
+    with pytest.raises(ValueError, match="fused Pallas Adam"):
+        AdaSplitTrainer(CFG, AdaSplitHParams(fused_server_adam=True),
+                        clients6)
+    on = _train(clients6, rounds=1, kappa=0.0, fused_server_adam=None)
+    off = _train(clients6, rounds=1, kappa=0.0, fused_server_adam=False)
     assert _max_leaf_diff(on.server_params, off.server_params) == 0.0
     assert _max_leaf_diff(on.masks, off.masks) == 0.0
 

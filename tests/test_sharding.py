@@ -305,11 +305,11 @@ def test_mesh_axes_from_mesh_device_counts(ndev):
     """MeshAxes.from_mesh over 1/2/8-device cohort meshes (AbstractMesh:
     no real devices needed — shape/axis metadata only)."""
     from jax.sharding import AbstractMesh
-    ax = MeshAxes.from_mesh(AbstractMesh((("data", ndev),)))
+    ax = MeshAxes.from_mesh(AbstractMesh((ndev,), ("data",)))
     assert ax.data == ("data",) and ax.data_size == ndev
     assert ax.model is None and ax.model_size == 1
     assert ax.data_spec == "data"
-    ax2 = MeshAxes.from_mesh(AbstractMesh((("data", ndev), ("model", 2))))
+    ax2 = MeshAxes.from_mesh(AbstractMesh((ndev, 2), ("data", "model")))
     assert ax2.data_size == ndev and ax2.model_size == 2
 
 
