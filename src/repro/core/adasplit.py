@@ -196,6 +196,7 @@ from repro.models import lenet
 from repro.optim.adam import adam_init, adam_update
 from repro.sharding.rules import (MeshAxes, cohort_pspecs,
                                   staged_cohort_spec)
+from repro.utils.spans import span
 
 
 @dataclass
@@ -1088,15 +1089,20 @@ class AdaSplitTrainer:
         base = self.orch._n_selects
 
         def stage(r0, rc):
-            rds = [rounds_data[r]() if callable(rounds_data[r])
-                   else rounds_data[r] for r in range(r0, r0 + rc)]
-            xs = np.stack([rd[0] for rd in rds])
-            ys = np.stack([rd[1] for rd in rds])
-            t_idx = (base + (r0 + np.arange(rc))[:, None] * T
-                     + np.arange(T)[None, :]).astype(np.int32)
-            return (self._put_staged(xs, cohort_dim=2),
-                    self._put_staged(ys, cohort_dim=2),
-                    jax.device_put(t_idx))
+            with span("train.feed"):
+                rds = [rounds_data[r]() if callable(rounds_data[r])
+                       else rounds_data[r] for r in range(r0, r0 + rc)]
+                with span("train.stack", bytes=sum(
+                        rd[0].nbytes + rd[1].nbytes for rd in rds)):
+                    xs = np.stack([rd[0] for rd in rds])
+                    ys = np.stack([rd[1] for rd in rds])
+                t_idx = (base + (r0 + np.arange(rc))[:, None] * T
+                         + np.arange(T)[None, :]).astype(np.int32)
+                with span("train.put",
+                          bytes=xs.nbytes + ys.nbytes + t_idx.nbytes):
+                    return (self._put_staged(xs, cohort_dim=2),
+                            self._put_staged(ys, cohort_dim=2),
+                            jax.device_put(t_idx))
 
         starts = list(range(0, R, chunk))
         ring = [stage(0, min(chunk, R))]            # slot 0: first chunk
@@ -1104,7 +1110,8 @@ class AdaSplitTrainer:
         for ci, r0 in enumerate(starts):
             rc = min(chunk, R - r0)
             fn = self._epoch_fn(rc, T, global_phase)
-            carry, outs = fn(carry, *ring.pop(0))   # async dispatch
+            with span("train.dispatch"):
+                carry, outs = fn(carry, *ring.pop(0))   # async dispatch
             if ci + 1 < len(starts):                # slot 1: next chunk's
                 n0 = starts[ci + 1]                 # H2D overlaps compute
                 ring.append(stage(n0, min(chunk, R - n0)))
@@ -1118,21 +1125,24 @@ class AdaSplitTrainer:
                     server_flops_per_example=self._fl_s,
                     host_device_bytes=self._staging_bytes_per_round(T))
         if global_phase:
-            fetched = jax.device_get(outs_all)      # the ONE epoch sync
-            idx_all = np.concatenate([f[0] for f in fetched])
-            ces_all = np.concatenate([f[1] for f in fetched])
-            fracs_all = np.concatenate([f[2] for f in fetched])
-            summaries = self.meter.ingest_epoch(
-                n_rounds=R, nnz_fracs=fracs_all if hp.act_l1 else None,
-                n_selected=idx_all.shape[-1],
-                grad_down=hp.server_grad_to_client,
-                interconnect_bytes=self._iteration_interconnect_bytes()
-                * T, **bill)
-            self.orch.ingest_epoch(idx_all, ces_all, state=ucb)
+            with span("train.sync"):
+                fetched = jax.device_get(outs_all)  # the ONE epoch sync
+            with span("train.bill"):
+                idx_all = np.concatenate([f[0] for f in fetched])
+                ces_all = np.concatenate([f[1] for f in fetched])
+                fracs_all = np.concatenate([f[2] for f in fetched])
+                summaries = self.meter.ingest_epoch(
+                    n_rounds=R, nnz_fracs=fracs_all if hp.act_l1 else None,
+                    n_selected=idx_all.shape[-1],
+                    grad_down=hp.server_grad_to_client,
+                    interconnect_bytes=self._iteration_interconnect_bytes()
+                    * T, **bill)
+                self.orch.ingest_epoch(idx_all, ces_all, state=ucb)
         else:
-            summaries = self.meter.ingest_epoch(n_rounds=R, n_selected=0,
-                                                **bill)
-            self.orch.ingest_epoch(None, None, state=ucb, n_rounds=R)
+            with span("train.bill"):
+                summaries = self.meter.ingest_epoch(n_rounds=R,
+                                                    n_selected=0, **bill)
+                self.orch.ingest_epoch(None, None, state=ucb, n_rounds=R)
         return summaries
 
     # ------------------------------------------------------------------
@@ -1465,7 +1475,8 @@ class AdaSplitTrainer:
         fl_c = self._fl_c
         use_scan = hp.round_scan and hp.global_batch
         if hp.epoch_scan and use_scan:
-            return self._train_epoch_scan(eval_every)
+            with span("train"):
+                return self._train_epoch_scan(eval_every)
         global_iter = (self._global_iteration if hp.global_batch
                        else self._global_iteration_loop)
 
@@ -1537,7 +1548,11 @@ class AdaSplitTrainer:
             """One round's staged data.  Called lazily by the staging
             ring — at most two chunks of batches are ever materialized
             on the host."""
-            return self._stage_round_np(make_iters(), T, self.n)
+            with span("train.draw"):
+                iters = make_iters()
+            with span("train.stack",
+                      bytes=int(self._staging_bytes_per_round(T))):
+                return self._stage_round_np(iters, T, self.n)
 
         r = 0
         while r < hp.rounds:
@@ -1557,15 +1572,17 @@ class AdaSplitTrainer:
                 summaries = self._run_epoch_streamed(R, T, global_phase,
                                                      make_iters)
             else:
-                summaries = self._run_epoch_scan([make_round] * R, T,
-                                                 global_phase)
+                with span("train.epoch"):
+                    summaries = self._run_epoch_scan([make_round] * R, T,
+                                                     global_phase)
             for j, rr in enumerate(range(r, end + 1)):
                 rec = {"round": rr,
                        "phase": "global" if global_phase else "local",
                        **(summaries[j] if j < len(summaries)
                           else self.meter.summary())}
                 if is_eval(rr):        # only possible at the epoch end
-                    rec["accuracy"] = self.evaluate()
+                    with span("train.eval"):
+                        rec["accuracy"] = self.evaluate()
                 self.history.append(rec)
             r = end + 1
         return self.history

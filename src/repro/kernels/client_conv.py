@@ -80,11 +80,12 @@ def im2col(x, k: int):
     h, w = x.shape[-3], x.shape[-2]
     pad = k // 2
     cfg = [(0, 0)] * (x.ndim - 3) + [(pad, pad), (pad, pad), (0, 0)]
-    xp = jnp.pad(x, cfg)
-    cols = [jax.lax.slice_in_dim(
-        jax.lax.slice_in_dim(xp, i, i + h, axis=-3), j, j + w, axis=-2)
-        for i in range(k) for j in range(k)]
-    return jnp.concatenate(cols, axis=-1)
+    with jax.named_scope("im2col"):   # names its ops, and their transpose
+        xp = jnp.pad(x, cfg)
+        cols = [jax.lax.slice_in_dim(
+            jax.lax.slice_in_dim(xp, i, i + h, axis=-3), j, j + w, axis=-2)
+            for i in range(k) for j in range(k)]
+        return jnp.concatenate(cols, axis=-1)
 
 
 def _panels(x, w):

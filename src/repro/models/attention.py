@@ -274,7 +274,8 @@ def attn_decode(p, x, cache, pos, cfg, *, window=0, kv_override=None,
             q = q + p["bq"].astype(dtype)
         q = q.reshape(B, 1, hq, hd)
         k_all, v_all = kv_override
-        out = mha_einsum(q, k_all, v_all, causal=False)
+        with jax.named_scope("decode_attention"):
+            out = mha_einsum(q, k_all, v_all, causal=False)
         out = _head_gate(out, head_gate, dtype)
         out = out.reshape(B, 1, hq * hd)
         return out @ p["wo"].astype(dtype), cache
@@ -313,7 +314,8 @@ def attn_decode(p, x, cache, pos, cfg, *, window=0, kv_override=None,
         else:
             valid = idx <= pos
         kv_valid = jnp.broadcast_to(valid[None, :], (B, L))
-    out = mha_einsum(q, k_all, v_all, causal=False, kv_valid=kv_valid)
+    with jax.named_scope("decode_attention"):
+        out = mha_einsum(q, k_all, v_all, causal=False, kv_valid=kv_valid)
     out = _head_gate(out, head_gate, dtype)
     out = out.reshape(B, 1, hq * hd)
     return out @ p["wo"].astype(dtype), {"k": k_all, "v": v_all}

@@ -50,6 +50,7 @@ from repro.models import decode as dec
 from repro.serve.engine import EngineStats, Request
 from repro.serve.lru import ShardedLRU
 from repro.serve.scheduler import SlotScheduler
+from repro.utils.spans import span
 
 
 def _bucket(n: int, cap: int) -> int:
@@ -137,7 +138,8 @@ class ContinuousEngine:
             if self.binarize_threshold > 0:
                 g = masks_mod.binarize(g, self.binarize_threshold)
             return g
-        g = self._gate_lru.get_or_add(client_id, build)
+        with span("serve.gate", hit=int(client_id in self._gate_lru)):
+            g = self._gate_lru.get_or_add(client_id, build)
         self.stats.gate_hits = self._gate_lru.hits
         self.stats.gate_misses = self._gate_lru.misses
         return g
@@ -157,21 +159,27 @@ class ContinuousEngine:
     def _do_admit(self, slot: int, req: Request, now: float):
         L = len(req.prompt)
         b = _bucket(L, self.cache_len)
-        prompt = np.zeros((1, b), np.int32)
-        prompt[0, :L] = req.prompt
-        gates_c = self._gates_for(req.client_id) \
-            if self.masks is not None else None
-        first_tok, one_cache = self._prefill_for(b)(
-            self.params, jnp.asarray(prompt),
-            jnp.asarray([L - 1], jnp.int32), gates_c)
-        self._cache, self._tok, self._outbuf, self._gates = self._admit_dev(
-            self._cache, self._tok, self._outbuf, self._gates,
-            jnp.asarray(slot, jnp.int32), one_cache, first_tok, gates_c)
+        wait_us = round((time.time() - req.t_submit) * 1e6)
+        with span("serve.admit", wait_us=wait_us):
+            prompt = np.zeros((1, b), np.int32)
+            prompt[0, :L] = req.prompt
+            gates_c = self._gates_for(req.client_id) \
+                if self.masks is not None else None
+            with span("serve.prefill"):
+                first_tok, one_cache = self._prefill_for(b)(
+                    self.params, jnp.asarray(prompt),
+                    jnp.asarray([L - 1], jnp.int32), gates_c)
+            with span("serve.merge"):
+                self._cache, self._tok, self._outbuf, self._gates = \
+                    self._admit_dev(self._cache, self._tok, self._outbuf,
+                                    self._gates, jnp.asarray(slot, jnp.int32),
+                                    one_cache, first_tok, gates_c)
         req.t_admit = now
         self.stats.tokens += 1          # prefill produced its first token
 
     def _finish(self, slot: int, req: Request):
-        row = np.asarray(self._outbuf[slot, : req.max_new_tokens])
+        with span("serve.finish"):
+            row = np.asarray(self._outbuf[slot, : req.max_new_tokens])
         req.output = row                 # forces the completing step
         req.t_done = time.time()
         req.latency_s = req.t_done - req.t_admit
@@ -183,6 +191,10 @@ class ContinuousEngine:
         """Admit into free slots, then one decode step for the whole
         batch.  Returns False when there is nothing in flight (caller
         may sleep / feed more traffic)."""
+        with span("serve.step"):
+            return self._step()
+
+    def _step(self) -> bool:
         progress = False
         while True:     # admission chains: a budget-1 request frees its
             now = time.time()            # slot before any decode step
@@ -205,9 +217,10 @@ class ContinuousEngine:
             s = self.sched.slots[i]
             pos[i] = s.pos               # free slots park at 0 / last col:
             gen_idx[i] = s.gen           # their rows are never read
-        self._tok, self._cache, self._outbuf = self._decode(
-            self.params, self._cache, self._tok, jnp.asarray(pos),
-            self._gates, self._outbuf, jnp.asarray(gen_idx))
+        with span("serve.decode"):
+            self._tok, self._cache, self._outbuf = self._decode(
+                self.params, self._cache, self._tok, jnp.asarray(pos),
+                self._gates, self._outbuf, jnp.asarray(gen_idx))
         n = self.sched.note_step()
         self.stats.decode_steps += 1
         self.stats.slot_steps += n
