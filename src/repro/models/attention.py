@@ -69,6 +69,11 @@ def mha_einsum(q, k, v, *, causal: bool, window: int = 0,
     """Plain einsum attention (small shapes / oracle).
 
     q: (B, Sq, Hq, hd); k, v: (B, Sk, Hkv, hd).  GQA via head grouping.
+    Serves training, prefill and the tests' oracle.  Single-token decode
+    has its own grouped path, :func:`gqa_decode`: a decode step reads the
+    whole cache to make one token, so the float32 K/V repeated to Hq
+    heads here would be written and read back for every layer and step,
+    many times the bytes of the cache itself.
     """
     B, Sq, Hq, hd = q.shape
     Hkv = k.shape[2]
@@ -93,6 +98,29 @@ def mha_einsum(q, k, v, *, causal: bool, window: int = 0,
     w = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", w, vf)
     return out.astype(q.dtype)
+
+
+def gqa_decode(q, k, v, kv_valid: Optional[jnp.ndarray] = None):
+    """Single-token attention on grouped KV heads, reading the cache once.
+
+    q: (B, 1, Hq, hd); k, v: (B, L, Hkv, hd) in the cache's dtype;
+    kv_valid: optional (B, L) bool.  q is viewed as (B, 1, Hkv, G, hd),
+    so K and V are never repeated to Hq heads nor upcast as a whole.
+    Score products accumulate in float32 (bfloat16 products are exact
+    there); the float32 probabilities meet V at ``HIGHEST`` precision.
+    """
+    B, Sq, Hq, hd = q.shape
+    Hkv = k.shape[2]
+    G = Hq // Hkv
+    qg = q.reshape(B, Sq, Hkv, G, hd)
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
+                        preferred_element_type=jnp.float32) / jnp.sqrt(hd)
+    if kv_valid is not None:  # (B, L) bool
+        scores = jnp.where(kv_valid[:, None, None, None, :], scores, NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", w, v.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    return out.reshape(B, Sq, Hq, hd).astype(q.dtype)
 
 
 def mha_chunked(q, k, v, *, causal: bool, window: int = 0,
@@ -275,7 +303,7 @@ def attn_decode(p, x, cache, pos, cfg, *, window=0, kv_override=None,
         q = q.reshape(B, 1, hq, hd)
         k_all, v_all = kv_override
         with jax.named_scope("decode_attention"):
-            out = mha_einsum(q, k_all, v_all, causal=False)
+            out = gqa_decode(q, k_all, v_all)
         out = _head_gate(out, head_gate, dtype)
         out = out.reshape(B, 1, hq * hd)
         return out @ p["wo"].astype(dtype), cache
@@ -315,7 +343,7 @@ def attn_decode(p, x, cache, pos, cfg, *, window=0, kv_override=None,
             valid = idx <= pos
         kv_valid = jnp.broadcast_to(valid[None, :], (B, L))
     with jax.named_scope("decode_attention"):
-        out = mha_einsum(q, k_all, v_all, causal=False, kv_valid=kv_valid)
+        out = gqa_decode(q, k_all, v_all, kv_valid)
     out = _head_gate(out, head_gate, dtype)
     out = out.reshape(B, 1, hq * hd)
     return out @ p["wo"].astype(dtype), {"k": k_all, "v": v_all}

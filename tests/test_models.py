@@ -142,6 +142,71 @@ def test_attention_chunked_matches_einsum():
         np.testing.assert_allclose(a, b, rtol=2e-2, atol=2e-2)
 
 
+def _oracle_decode(q, k, v, kv_valid=None):
+    from repro.models.attention import mha_einsum
+    return mha_einsum(q, k, v, causal=False, kv_valid=kv_valid)
+
+
+@pytest.mark.parametrize("mode,G", [("slots", 1), ("slots", 2), ("slots", 7),
+                                    ("scalar", 7), ("window", 7),
+                                    ("cross", 7), ("f32_query", 7)])
+def test_gqa_decode_matches_einsum_oracle(monkeypatch, mode, G):
+    """attn_decode on grouped KV heads over a bfloat16 cache == the same
+    step with ``mha_einsum`` (KV repeated to Hq heads in float32) in its
+    place.  Per-slot positions hold an empty slot (no valid key) and a
+    slot at the cache's last row; the ring is full and wrapped.  Keys are
+    drawn wide enough that the softmax is peaked, so the scale and the
+    mask both show.  ``f32_query`` calls gqa_decode itself with a float32
+    query: its float32 output must carry the oracle's precision, which
+    probabilities rounded to bfloat16 would not."""
+    import dataclasses
+    from repro.models import attention as attn
+    cfg = dataclasses.replace(get_config("qwen2-0.5b").reduced(),
+                              n_kv_heads=2, n_heads=2 * G)
+    bf = jnp.bfloat16
+    p = jax.tree.map(lambda a: a.astype(bf),
+                     attn.attention_init(jax.random.PRNGKey(3), cfg))
+    rng = np.random.default_rng(G)
+    B, L = 4, 16
+    x = jnp.asarray(rng.normal(0, 1.0, (B, 1, cfg.d_model)), bf)
+    cache = {n: jnp.asarray(rng.normal(0, sd, (B, L, cfg.n_kv_heads,
+                                               cfg.head_dim)), bf)
+             for n, sd in (("k", 3.0), ("v", 1.0))}
+    pos = {"slots": jnp.asarray([-1, 0, 9, L - 1], jnp.int32),
+           "scalar": jnp.asarray(9, jnp.int32),
+           "window": jnp.asarray(L + 5, jnp.int32),
+           "cross": jnp.asarray(0, jnp.int32)}.get(mode)
+    if mode == "f32_query":
+        q = jnp.asarray(rng.normal(0, 1.0, (B, 1, cfg.n_heads,
+                                            cfg.head_dim)), jnp.float32)
+        valid = jnp.arange(L)[None, :] <= jnp.asarray([-1, 0, 9, L - 1])[:, None]
+        got = attn.gqa_decode(q, cache["k"], cache["v"], valid)
+        want = _oracle_decode(q, cache["k"], cache["v"], valid)
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+        return
+
+    def run():
+        if mode == "cross":
+            return attn.attn_decode(p, x, None, pos, cfg,
+                                    kv_override=(cache["k"], cache["v"]))
+        return attn.attn_decode(p, x, cache, pos, cfg,
+                                window=L if mode == "window" else 0)
+
+    got, got_cache = run()
+    monkeypatch.setattr(attn, "gqa_decode", _oracle_decode)
+    want, want_cache = run()
+    assert got.dtype == bf
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=1e-2, atol=1e-2)
+    if mode != "cross":
+        for n in "kv":
+            assert got_cache[n].dtype == bf
+            np.testing.assert_array_equal(np.asarray(got_cache[n]),
+                                          np.asarray(want_cache[n]))
+
+
 def test_param_counts_match_model_cards():
     """Analytic param counts should land near the named model sizes."""
     expect = {

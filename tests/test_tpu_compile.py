@@ -122,3 +122,29 @@ def test_fused_adam_compiles_for_v5e_masks(one_chip, C):
     step = jax.vmap(lambda p, g, o: fused_adam_update(p, g, o, lr=1e-3))
     assert "masked_adam" in _kernels(step, _sds(m, one_chip),
                                      _sds(m, one_chip), _sds(opt, one_chip))
+
+
+def test_decode_attention_grouped_for_v5e(one_chip):
+    """One Qwen2-0.5B decode layer as the serving engine runs it (64
+    slots, per-slot positions, a 1280-row bfloat16 cache, 14 query heads
+    on 2 KV heads of 64) compiles with little scratch and never
+    materialises a float32 K/V (or score) array repeated to 14 heads
+    over the cache's rows: attention reads the bfloat16 cache as it is."""
+    import re
+    from repro.models import attention as attn
+    cfg = get_config("qwen2-0.5b")
+    B, L, bf = 64, 1280, jnp.bfloat16
+    p = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, bf, sharding=one_chip),
+        jax.eval_shape(lambda: attn.attention_init(jax.random.PRNGKey(0),
+                                                   cfg)))
+    x = jax.ShapeDtypeStruct((B, 1, cfg.d_model), bf, sharding=one_chip)
+    cache = {n: jax.ShapeDtypeStruct((B, L, cfg.n_kv_heads, cfg.head_dim),
+                                     bf, sharding=one_chip) for n in "kv"}
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lambda p, x, c, pos: attn.attn_decode(
+        p, x, c, pos, cfg)).lower(p, x, cache, pos).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2**20
+    dims = [s.split(",") for s in
+            re.findall(r"f32\[([0-9,]+)\]", compiled.as_text())]
+    assert not [d for d in dims if str(cfg.n_heads) in d and str(L) in d]
